@@ -17,10 +17,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
-try:  # py3.11+
-    import tomllib as _toml
-except ImportError:  # py3.10: pytest's bundled tomli dependency
-    import tomli as _toml  # type: ignore[no-redef]
+import tomllib as _toml
 
 from repro.analysis.base import RULES, Finding
 

@@ -7,13 +7,18 @@ adaptor presents Salus as a virtual device.
 
 Memory profiles are measured automatically by compiling one step
 (``profiles.profile_executable``) when not supplied — the adaptor is the
-only component that touches jit/compile, keeping user code unchanged.
+only component that touches jit/compile, keeping user code unchanged. The
+step is compiled from the shapes of the state and of one batch, for the
+executor's device, so profiling puts no state on the device; the session
+then runs that executable, and its first iteration does not compile again.
 """
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.executor import ExecutorReport, SalusExecutor
 from repro.core.profiles import profile_executable
@@ -48,9 +53,18 @@ class VirtualDevice:
         ``request_times`` makes the session an open-loop inference service:
         iteration k serves the request arriving at ``request_times[k]``."""
         jitted = jax.jit(step_fn) if not hasattr(step_fn, "lower") else step_fn
+        executable = None
         if profile is None:
-            compiled = jitted.lower(init_state, data_fn(0)).compile()
-            profile = profile_executable(compiled)
+            device = getattr(self.executor, "device", None)
+            sharding = SingleDeviceSharding(device) if device is not None else None
+            shapes = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    jnp.shape(x), jnp.result_type(x), sharding=sharding
+                ),
+                (init_state, data_fn(0)),
+            )
+            executable = jitted.lower(*shapes).compile()
+            profile = profile_executable(executable)
         sess = Session(
             name=name,
             step_fn=jitted,
@@ -64,6 +78,7 @@ class VirtualDevice:
             arrival_time=arrival_time,
             priority=priority,
             request_times=request_times,
+            executable=executable,
         )
         self._sessions.append(sess)
         self.executor.submit(sess)

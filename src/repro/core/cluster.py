@@ -685,10 +685,12 @@ class ClusterExecutor(_RebalanceMixin):
     source, ``jax.device_put`` on the destination — compose
     :func:`repro.dist.elastic.restore_on_mesh` via
     ``SalusExecutor.migrate_in``'s ``put_fn`` for mesh-aware landings).
-    ``bind_jax_devices=True`` pins executor *i*'s transfers to
-    ``jax.devices()[i % len]`` — with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the CI
-    recipe) each worker then really owns a distinct XLA device."""
+    A session's state lands on its executor's device when that device
+    admits it. ``bind_jax_devices=True`` binds executor *i* to
+    ``jax.devices()[i]``, so each worker owns a distinct device (one chip
+    each on a four-chip host; on CPU, force N host devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``); asking for
+    more executors than there are devices raises."""
 
     def __init__(
         self,
@@ -719,7 +721,12 @@ class ClusterExecutor(_RebalanceMixin):
             import jax
 
             avail = jax.devices()
-            devices = [avail[i % len(avail)] for i in range(n_devices)]
+            if n_devices > len(avail):
+                raise ValueError(
+                    f"bind_jax_devices: {n_devices} executors but only "
+                    f"{len(avail)} devices"
+                )
+            devices = list(avail[:n_devices])
         self.executors = [
             SalusExecutor(
                 self.placer.capacities[i],

@@ -4,7 +4,10 @@ Single-process, owns the device; sessions register (1a), get a lane from
 the memory manager (1b), and their iterations are scheduled (2a/2b) at
 iteration granularity by the configured policy. Persistent state (param
 arrays) never leaves the device between switches — switching cost is just
-dispatching a different executable, measured and reported.
+dispatching a different executable, measured and reported. A session's
+state is on the device only while it holds a lane: it is placed there when
+the memory manager admits the job and moved back to host when the job
+finishes or fails, so queued jobs hold no device memory.
 
 Memory admission goes through the shared :class:`MemoryManager` (the same
 decision logic, verbatim, that the discrete-event simulator runs): deficit
@@ -78,12 +81,10 @@ class SalusExecutor:
     ) -> None:
         if accounting not in ("wall", "nominal"):
             raise ValueError(f"accounting must be wall|nominal, got {accounting!r}")
-        # optional jax.Device this executor's transfers land on (None =
-        # backend default). The concurrent fleet driver binds executor i to
-        # jax.devices()[i % len] so, with
-        # XLA_FLAGS=--xla_force_host_platform_device_count=N, each worker
-        # thread really owns a distinct XLA device.
-        self.device = device
+        # the jax.Device admitted sessions are placed on (None = the
+        # backend's default device). The fleet binds executor i to
+        # jax.devices()[i], so each worker thread owns a distinct device.
+        self.device = device if device is not None else jax.devices()[0]
         self.registry = LaneRegistry(capacity)
         self.memory = MemoryManager(self.registry, memory, pager=self._do_transfer)
         self.memory.on_admit = self._on_admit
@@ -136,6 +137,7 @@ class SalusExecutor:
             raise ValueError(
                 f"duplicate job_id {job.job_id} ({job.name!r}): already submitted"
             )
+        session.release()  # state waits on the host until admission
         self.sessions[job.job_id] = session
         self.stats[job.job_id] = JobStats(arrival_time=self.now())
         self.state[job.job_id] = JobState.QUEUED
@@ -153,10 +155,9 @@ class SalusExecutor:
         t0 = time.perf_counter()
         if sess is not None:
             if direction == "out":
-                sess.state = jax.device_get(sess.state)
+                sess.release()
             else:
-                sess.state = jax.device_put(sess.state, self.device)
-                jax.block_until_ready(sess.state)
+                sess.place(self.device)
         dt = time.perf_counter() - t0
         self.transfer_latencies.append(dt)
         return dt
@@ -168,6 +169,7 @@ class SalusExecutor:
         return job.profile.persistent / self.memory.config.page_bandwidth
 
     def _on_admit(self, job: JobSpec, lane: Lane) -> None:
+        self.sessions[job.job_id].place(self.device)
         st = self.stats[job.job_id]
         if st.admit_time is None:
             st.admit_time = self.now()
@@ -259,6 +261,7 @@ class SalusExecutor:
             st.failed = True
             self.failures[job.job_id] = f"{type(exc).__name__}: {exc}"
             self._last_ran = None
+            sess.release()
             self.memory.job_finish(job, self._clock())
             return
         end = self.now()
@@ -282,6 +285,7 @@ class SalusExecutor:
             self.state[job.job_id] = JobState.FINISHED
             st.finish_time = end
             self._last_ran = None
+            sess.release()  # before job_finish: the retry may admit a queued job
             self.memory.job_finish(job, self._clock())
         else:
             self.state[job.job_id] = JobState.READY
@@ -356,9 +360,10 @@ class SalusExecutor:
         back onto the device (``put_fn`` defaults to ``jax.device_put``;
         pass a mesh-aware restore — e.g. a ``dist.elastic.restore_on_mesh``
         closure — to re-shard onto a different device layout), then run the
-        ordinary admission path. ``extra_delay`` is the source-side modeled
-        cost from ``migrate_out``, charged to the nominal clock before this
-        job's first iteration here."""
+        ordinary admission path; a job that is not admitted goes back to
+        host. ``extra_delay`` is the source-side modeled cost from
+        ``migrate_out``, charged to the nominal clock before this job's
+        first iteration here."""
         job = session.job
         jid = job.job_id
         self.sessions[jid] = session
@@ -376,7 +381,8 @@ class SalusExecutor:
             self.transfer_latencies.append(cost)
         # logs MIGRATE_IN (the on-event hook charges the modeled in-cost to
         # the nominal clock), then admission: admit / queue / reject
-        self.memory.migrate_in(job, self._clock(), cost=cost)
+        if self.memory.migrate_in(job, self._clock(), cost=cost) is None:
+            session.release()
 
     # ------------------------------------------------------------------
 

@@ -1,17 +1,13 @@
 """jit'd public wrapper: model layout (b, s, h, d) <-> kernel layout
-(b, h, s, d), interpret-mode selection on CPU hosts."""
+(b, h, s, d). The kernel runs compiled for the TPU unless the caller asks
+for the interpreter with ``interpret=True``."""
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(
@@ -24,10 +20,8 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     q_offset: int = 0,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    if interpret is None:
-        interpret = not _on_tpu()
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

@@ -1,9 +1,9 @@
-"""Public wrapper: arbitrary leading dims, interpret selection on CPU."""
+"""Public wrapper: arbitrary leading dims. The kernel runs compiled for the
+TPU unless the caller asks for the interpreter with ``interpret=True``."""
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.fused_rmsnorm.kernel import fused_rmsnorm
@@ -15,10 +15,8 @@ def rmsnorm(
     residual: Optional[jnp.ndarray] = None,
     *,
     eps: float = 1e-6,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     shape = x.shape
     rows = 1
     for s in shape[:-1]:

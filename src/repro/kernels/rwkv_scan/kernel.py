@@ -40,11 +40,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfin_ref, state_scr, *
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)  # (L, dv)
     w = w_ref[0, 0].astype(jnp.float32)  # (L, dk), in (0, 1)
-    u = u_ref[0].astype(jnp.float32)  # (dk,)
+    u = u_ref[0].astype(jnp.float32)  # (1, dk)
     L = r.shape[0]
 
+    tpos = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    spos = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     logw = jnp.log(w)
-    cum = jnp.cumsum(logw, axis=0)  # (L, dk); cum[t] = sum_{s<=t} log w_s
+    # cum[t] = sum_{s<=t} log w_s as a lower-triangular matmul: Mosaic has
+    # no cumsum lowering, and fp32 contraction keeps the decays exact
+    cum = jax.lax.dot_general(
+        (tpos >= spos).astype(jnp.float32), logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # (L, dk)
     cum_prev = cum - logw  # cum[t-1], zero at t=0
 
     state = state_scr[...]  # (dk, dv)
@@ -55,22 +62,26 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfin_ref, state_scr, *
     )  # (L, dv)
     # intra-chunk: pairwise strictly-lower-triangular scores with decay
     decay = jnp.exp(cum_prev[:, None, :] - cum[None, :, :])  # (t, s, dk)
-    tpos = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    spos = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     mask = (tpos > spos).astype(jnp.float32)
-    scores = jnp.einsum("tc,sc,tsc->ts", r, k, decay) * mask  # (L, L)
+    scores = jnp.sum(r[:, None, :] * k[None, :, :] * decay, axis=-1) * mask  # (L, L)
     o_intra = jax.lax.dot_general(
         scores, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     # diagonal bonus
-    diag = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)  # (L, 1)
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)  # (L, 1)
     o = o_inter + o_intra + diag * v
     o_ref[0, 0] = o.astype(o_ref.dtype)
 
     # state update to end of chunk
-    decay_to_end = jnp.exp(cum[-1:, :] - cum)  # (L, dk)
-    k_dec = k * decay_to_end
-    state_scr[...] = jnp.exp(cum[-1])[:, None] * state + jax.lax.dot_general(
+    cum_last = jnp.sum(logw, axis=0, keepdims=True)  # (1, dk)
+    k_dec = k * jnp.exp(cum_last - cum)  # (L, dk)
+    # e^{cum_L} along the state's rows, as a (dk, dv) matrix: a transposed
+    # contraction, since a (1, dk) -> (dk, 1) relayout does not lower
+    row_decay = jnp.exp(jax.lax.dot_general(
+        logw, jnp.ones((L, v.shape[1]), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    ))
+    state_scr[...] = row_decay * state + jax.lax.dot_general(
         k_dec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
@@ -105,7 +116,7 @@ def wkv6_bhsd(
             pl.BlockSpec((1, 1, chunk, dk), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, 1, chunk, dv), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, 1, chunk, dk), lambda ib, ih, ic: (ib, ih, ic, 0)),
-            pl.BlockSpec((1, dk), lambda ib, ih, ic: (ih, 0)),
+            pl.BlockSpec((1, 1, dk), lambda ib, ih, ic: (ih, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, dv), lambda ib, ih, ic: (ib, ih, ic, 0)),
@@ -117,5 +128,5 @@ def wkv6_bhsd(
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(h, 1, dk))
     return o, s_final

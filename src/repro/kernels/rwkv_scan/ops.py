@@ -1,17 +1,13 @@
 """jit'd public wrapper for the WKV6 kernel: model layout (b, s, h, d) <->
-kernel layout (b, h, s, d), interpret selection on CPU."""
+kernel layout (b, h, s, d). The kernel runs compiled for the TPU unless the
+caller asks for the interpreter with ``interpret=True``."""
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.rwkv_scan.kernel import wkv6_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def wkv6(
@@ -22,10 +18,8 @@ def wkv6(
     u: jnp.ndarray,  # (h, dk)
     *,
     chunk: int = 64,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if interpret is None:
-        interpret = not _on_tpu()
     tr = lambda t: jnp.swapaxes(t, 1, 2).astype(jnp.float32)
     o, s_final = wkv6_bhsd(
         tr(r), tr(k), tr(v), tr(w), u.astype(jnp.float32), chunk=chunk, interpret=interpret

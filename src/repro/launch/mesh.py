@@ -9,13 +9,9 @@ import jax
 
 
 def _make(shape, axes):
-    # axis_types / AxisType landed after jax 0.4.x; Auto is the default
-    # behavior there, so only pass it where the API exists.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

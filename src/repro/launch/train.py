@@ -31,6 +31,7 @@ from repro.dist.fault import (
     StragglerMonitor,
 )
 from repro.dist.sharding import batch_shardings, make_context, param_shardings
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import ModelOptions, build_model
 from repro.train.grad_compress import ErrorFeedbackCompressor
@@ -55,6 +56,7 @@ def main(argv=None):
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

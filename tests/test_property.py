@@ -5,12 +5,9 @@ registry maintains the paper's safety condition, contiguous lane layout,
 and refcount consistency — and admission is monotone (finishing a job never
 evicts an admitted one).
 """
+import hypothesis.strategies as st
 import numpy as np
-import pytest
-
-hypothesis = pytest.importorskip("hypothesis")
-import hypothesis.strategies as st  # noqa: E402
-from hypothesis import given, settings  # noqa: E402
+from hypothesis import given, settings
 
 from repro.core import GB, MB, JobSpec, LaneRegistry, MemoryProfile
 from repro.core.simulator import Simulator
