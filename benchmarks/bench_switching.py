@@ -3,8 +3,9 @@
 (a) Theoretical checkpoint-transfer time (Gandiva-style suspend/resume
     moving persistent memory over PCIe at 30 GB/s, the paper's number) vs
     model inference latency — the motivation for keep-resident switching;
-(b) Salus's measured live switch bookkeeping latency (keep-resident: zero
-    bytes moved) from the executor benches."""
+(b) Salus's measured live switch latency on the host (keep-resident: zero
+    bytes moved): from one job's wait on the device to the return of the
+    next one's dispatch, from the executor's span log."""
 from __future__ import annotations
 
 import jax

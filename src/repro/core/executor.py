@@ -4,7 +4,8 @@ Single-process, owns the device; sessions register (1a), get a lane from
 the memory manager (1b), and their iterations are scheduled (2a/2b) at
 iteration granularity by the configured policy. Persistent state (param
 arrays) never leaves the device between switches — switching cost is just
-dispatching a different executable, measured and reported. A session's
+dispatching a different executable, measured and reported from the
+executor's span log (``core/spans.py``; ``ExecutorReport.spans``). A session's
 state is on the device only while it holds a lane: it is placed there when
 the memory manager admits the job and moved back to host when the job
 finishes or fails, so queued jobs hold no device memory.
@@ -34,7 +35,9 @@ per turn, which preserves the serialization-within-lane invariant.
 from __future__ import annotations
 
 import time
+from bisect import bisect_left
 from dataclasses import dataclass, field
+from itertools import accumulate
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -44,6 +47,7 @@ from repro.core.lanes import Lane, LaneRegistry
 from repro.core.memory import MemoryConfig, MemoryManager
 from repro.core.scheduler import Policy, get_policy
 from repro.core.session import Session
+from repro.core.spans import SpanLog
 from repro.core.types import (
     IterationRecord,
     JobSpec,
@@ -65,6 +69,7 @@ class ExecutorReport(ResultSurface):
     memory_events: List[MemoryEvent] = field(default_factory=list)
     decision_log: DecisionLog = field(default_factory=DecisionLog)
     failures: Dict[int, str] = field(default_factory=dict)  # job_id -> error
+    spans: Optional[SpanLog] = field(default=None, compare=False, repr=False)
 
     # avg_jct / p95_jct / jcts / utilization / completed / per_job /
     # request_latencies come from ResultSurface.
@@ -95,10 +100,9 @@ class SalusExecutor:
         self.stats: Dict[int, JobStats] = {}
         self.state: Dict[int, JobState] = {}
         self.records: List[IterationRecord] = []
-        self.switch_latencies: List[float] = []
-        self.transfer_latencies: List[float] = []
+        self.spans = SpanLog()
         self.failures: Dict[int, str] = {}  # job_id -> "ExcType: message"
-        self._last_job_on: Dict[int, int] = {}
+        self._prev_on_device: Optional[int] = None  # job of the last iteration run
         self._last_ran: Optional[int] = None  # job whose iteration just ended
         self._t0: Optional[float] = None
         # Nominal virtual clock: replicates the simulator's time semantics
@@ -137,6 +141,7 @@ class SalusExecutor:
             raise ValueError(
                 f"duplicate job_id {job.job_id} ({job.name!r}): already submitted"
             )
+        session.spans = self.spans
         session.release()  # state waits on the host until admission
         self.sessions[job.job_id] = session
         self.stats[job.job_id] = JobStats(arrival_time=self.now())
@@ -152,15 +157,49 @@ class SalusExecutor:
         Paged-out state lives as host (numpy) buffers; page-in puts it back
         on the device and blocks until resident."""
         sess = self.sessions.get(job.job_id)
-        t0 = time.perf_counter()
-        if sess is not None:
-            if direction == "out":
-                sess.release()
-            else:
-                sess.place(self.device)
-        dt = time.perf_counter() - t0
-        self.transfer_latencies.append(dt)
-        return dt
+        with self.spans.span("executor.page", job.name) as move:
+            if sess is not None:
+                if direction == "out":
+                    sess.release()
+                else:
+                    sess.place(self.device)
+        return move.seconds
+
+    @property
+    def transfer_latencies(self) -> List[float]:
+        """Seconds of each move of a session's state by the pager or by a
+        migration landing here, from the span log. The log is bounded
+        (``spans.BOUND`` spans), so in a long run this covers only the
+        moves still held in it, the latest."""
+        return [s.seconds for s in self.spans.of("executor.page", "executor.migrate")]
+
+    @property
+    def switch_latencies(self) -> List[float]:
+        """The host side of each switch still in the span log: from the end
+        of the outgoing job's ``session.block`` to the return of the
+        incoming job's ``session.dispatch``, less any wait for a request
+        in between. A switch is an iteration whose predecessor on the
+        device was another job's. A job's first iteration is left out:
+        where its step was not compiled ahead, that dispatch compiles it.
+        The log is bounded, so in a long run this covers only the latest
+        switches, while ``spans.counters["switches"]`` counts them all."""
+        marks: Dict[int, Dict[str, int]] = {}
+        for s in self.spans.spans:
+            if s.name in ("session.block", "session.dispatch") and s.parent is not None:
+                marks.setdefault(id(s.parent), {})[s.name] = s.t1
+        its = self.spans.of("iteration")
+        waits = self.spans.of("executor.wait")
+        wait_t0 = [w.t0 for w in waits]
+        waited = list(accumulate((w.t1 - w.t0 for w in waits), initial=0))
+        out = []
+        for a, b in zip(its, its[1:]):
+            end = marks.get(id(a), {}).get("session.block")
+            start = marks.get(id(b), {}).get("session.dispatch")
+            if a.job == b.job or b.index == 0 or end is None or start is None:
+                continue
+            between = waited[bisect_left(wait_t0, start)] - waited[bisect_left(wait_t0, end)]
+            out.append((start - end - between) / 1e9)
+        return out
 
     def _modeled_cost(self, job: JobSpec) -> float:
         """The simulator's transfer model (P / page_bandwidth), tracked in
@@ -234,22 +273,21 @@ class SalusExecutor:
         ]
 
     def _run_one(self, lane: Lane, job: JobSpec) -> None:
-        t_enter = time.perf_counter()
-        sess = self.sessions[job.job_id]
         st = self.stats[job.job_id]
+        with self.spans.span("iteration", job.name, st.iterations_done):
+            self._iterate(lane, job, st)
+
+    def _iterate(self, lane: Lane, job: JobSpec, st: JobStats) -> None:
+        sess = self.sessions[job.job_id]
         now = self.now()
         if st.first_run_time is None:
             st.first_run_time = now
-        prev = self._last_job_on.get(lane.lane_id)
-        self._last_job_on[lane.lane_id] = job.job_id
+        if self._prev_on_device not in (None, job.job_id):
+            # a fast switch: persistent memory stayed resident, so nothing
+            # moves; switch_latencies gives its host cost
+            self.spans.counters["switches"] += 1
+        self._prev_on_device = job.job_id
         self.state[job.job_id] = JobState.RUNNING
-        if prev is not None and prev != job.job_id:
-            # fast-switch cost: executor bookkeeping + dispatch setup between
-            # the scheduling decision and the step launch. Persistent memory
-            # stayed resident, so there is NO checkpoint transfer component
-            # (contrast: bench_switching computes the Gandiva-style transfer
-            # lower bound for the same jobs).
-            self.switch_latencies.append(time.perf_counter() - t_enter)
         try:
             dur = sess.run_iteration(st.iterations_done)
         except Exception as exc:  # noqa: BLE001 — any step_fn/data_fn error
@@ -293,7 +331,8 @@ class SalusExecutor:
         # second-chance tick: between iterations the ephemeral region is
         # empty, so pending jobs may be re-admitted and P pages may move
         # (memory-event stamps use the same clock request gating does)
-        self.memory.iteration_boundary(self._clock())
+        with self.spans.span("executor.tick"):
+            self.memory.iteration_boundary(self._clock())
 
     def _done(self) -> bool:
         return all(
@@ -371,14 +410,14 @@ class SalusExecutor:
         self.state[jid] = JobState.QUEUED
         if extra_delay:
             self._vtransfer[jid] = self._vtransfer.get(jid, 0.0) + extra_delay
+        session.spans = self.spans
         cost = None
         if session.state is not None:
-            t0 = time.perf_counter()
-            put = put_fn or (lambda tree: jax.device_put(tree, self.device))
-            session.state = put(session.state)
-            jax.block_until_ready(session.state)
-            cost = time.perf_counter() - t0
-            self.transfer_latencies.append(cost)
+            with self.spans.span("executor.migrate", job.name) as move:
+                put = put_fn or (lambda tree: jax.device_put(tree, self.device))
+                session.state = put(session.state)
+                jax.block_until_ready(session.state)
+            cost = move.seconds
         # logs MIGRATE_IN (the on-event hook charges the modeled in-cost to
         # the nominal clock), then admission: admit / queue / reject
         if self.memory.migrate_in(job, self._clock(), cost=cost) is None:
@@ -413,14 +452,15 @@ class SalusExecutor:
                 break
             progressed = False
             if self.policy.exclusive:
-                ready = [
-                    j for lane in self.registry.lanes.values() for j in self._candidates(lane)
-                ]
-                # decisions run on _clock() so FAIR rates and PRIORITY aging
-                # compare trace-relative arrival/last-run times against a
-                # clock in the same domain (virtual under nominal, wall from
-                # run() entry otherwise)
-                job = self.policy.select(ready, self.stats, self._clock(), blocked=blocked())
+                with self.spans.span("executor.pick"):
+                    ready = [
+                        j for lane in self.registry.lanes.values() for j in self._candidates(lane)
+                    ]
+                    # decisions run on _clock() so FAIR rates and PRIORITY
+                    # aging compare trace-relative arrival/last-run times
+                    # against a clock in the same domain (virtual under
+                    # nominal, wall from run() entry otherwise)
+                    job = self.policy.select(ready, self.stats, self._clock(), blocked=blocked())
                 if job is not None:
                     # genuine preemption only: the job whose iteration just
                     # ended, still a candidate, displaced by another pick
@@ -441,9 +481,10 @@ class SalusExecutor:
                 for lane in list(self.registry.lanes.values()):
                     if lane.lane_id not in self.registry.lanes:
                         continue  # lane deleted by a finish earlier this sweep
-                    job = self.policy.select(
-                        self._candidates(lane), self.stats, self._clock(), blocked=blocked()
-                    )
+                    with self.spans.span("executor.pick"):
+                        job = self.policy.select(
+                            self._candidates(lane), self.stats, self._clock(), blocked=blocked()
+                        )
                     if job is not None:
                         self._run_one(lane, job)
                         progressed = True
@@ -457,7 +498,9 @@ class SalusExecutor:
                 # one more boundary tick: paging / second chance may unblock
                 # (the simulator runs the identical tick loop whenever its
                 # device goes idle with queued/paged jobs)
-                if self.memory.iteration_boundary(self._clock()):
+                with self.spans.span("executor.tick"):
+                    unblocked = self.memory.iteration_boundary(self._clock())
+                if unblocked:
                     continue
                 # open-loop gap: nothing runnable until the next request
                 # arrives — jump the virtual clock (nominal) or really wait
@@ -469,7 +512,8 @@ class SalusExecutor:
                     if self.accounting == "nominal":
                         self._vnow = nxt
                     else:
-                        time.sleep(max(0.0, nxt - self._clock()))
+                        with self.spans.span("executor.wait"):
+                            time.sleep(max(0.0, nxt - self._clock()))
                     continue
                 if until is not None:
                     # epoch horizon: nothing runnable before it — hand back
@@ -503,6 +547,7 @@ class SalusExecutor:
             memory_events=self.memory.events,
             decision_log=DecisionLog(self.memory.decision_log()),
             failures=dict(self.failures),
+            spans=self.spans,
         )
 
     # Engine-protocol accessors -----------------------------------------

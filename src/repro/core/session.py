@@ -9,6 +9,10 @@ The state lives on the host while the job holds no lane: before admission,
 while paged out, and after the job ends. The executor calls :meth:`place`
 when the memory manager admits the job and :meth:`release` when it lets go
 of it, so the device holds only what admission control has counted.
+
+Each of those calls, and each iteration's input, dispatch and wait for the
+device, is a span in :attr:`Session.spans`: the executor's log once the
+session is submitted (``core/spans.py``).
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ from typing import Any, Callable, Dict, Iterator, Optional
 
 import jax
 
+from repro.core.spans import SpanLog
 from repro.core.types import JobSpec, MemoryProfile
 
 
@@ -48,6 +53,7 @@ class Session:
         self.n_iters = n_iters
         self.iterations_run = 0
         self.metrics_log = []
+        self.spans = SpanLog()  # the executor hands its own at submit
         self.job = JobSpec(
             name=name,
             profile=profile,
@@ -68,30 +74,36 @@ class Session:
         ahead-of-time executable compiled for another device (a fleet
         places jobs after profiling them) is compiled again here, for
         where the state now lives, so the first iteration does not."""
-        leaves = jax.tree_util.tree_leaves(self.state)
-        if any(not isinstance(x, jax.Array) for x in leaves):
-            self.state = jax.device_put(self.state, device)
-            jax.block_until_ready(self.state)
-        if self.executable is not None and not _compiled_for(self.executable, device):
-            self.executable = self.step_fn.lower(self.state, self.data_fn(0)).compile()
+        with self.spans.span("session.place", self.name):
+            leaves = jax.tree_util.tree_leaves(self.state)
+            if any(not isinstance(x, jax.Array) for x in leaves):
+                self.state = jax.device_put(self.state, device)
+                jax.block_until_ready(self.state)
+            if self.executable is not None and not _compiled_for(self.executable, device):
+                with self.spans.span("session.compile", self.name):
+                    self.executable = self.step_fn.lower(self.state, self.data_fn(0)).compile()
 
     def release(self) -> None:
         """Move the state to host (numpy) buffers, freeing its device memory."""
-        self.state = jax.device_get(self.state)
+        with self.spans.span("session.release", self.name):
+            self.state = jax.device_get(self.state)
 
     def run_iteration(self, index: int) -> float:
         """Execute one iteration on-device; returns wall seconds. Blocks
         until the computation is done (the executor serializes within a
         lane, matching iteration-granularity scheduling)."""
         t0 = time.perf_counter()
-        batch = self.data_fn(index)
+        with self.spans.span("session.data_fn", self.name, index):
+            batch = self.data_fn(index)
         fn = self.executable if self.executable is not None else self.step_fn
-        out = fn(self.state, batch)
+        with self.spans.span("session.dispatch", self.name, index):
+            out = fn(self.state, batch)
         if isinstance(out, tuple):
             self.state, metrics = out
         else:
             self.state, metrics = out, None
-        jax.block_until_ready(self.state)
+        with self.spans.span("session.block", self.name, index):
+            jax.block_until_ready(self.state)
         self.iterations_run += 1
         if metrics is not None:
             self.metrics_log.append(metrics)
