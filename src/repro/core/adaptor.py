@@ -11,6 +11,8 @@ only component that touches jit/compile, keeping user code unchanged. The
 step is compiled from the shapes of the state and of one batch, for the
 executor's device, so profiling puts no state on the device; the session
 then runs that executable, and its first iteration does not compile again.
+What the step's code counts as it is traced (``spans.count``) goes into the
+executor's span log.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.executor import ExecutorReport, SalusExecutor
 from repro.core.profiles import profile_executable
 from repro.core.session import Session
+from repro.core.spans import counting
 from repro.core.types import MemoryProfile
 
 
@@ -63,7 +66,10 @@ class VirtualDevice:
                 ),
                 (init_state, data_fn(0)),
             )
-            executable = jitted.lower(*shapes).compile()
+            log = getattr(self.executor, "spans", None)
+            with counting(log.counters if log is not None else {}):
+                lowered = jitted.lower(*shapes)
+            executable = lowered.compile()
             profile = profile_executable(executable)
         sess = Session(
             name=name,

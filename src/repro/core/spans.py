@@ -12,6 +12,13 @@ spans per iteration, so the bound holds some 8,000 iterations (about nine
 minutes of 70 ms iterations, and a 30 s benchmark window's 4,000 spans
 many times over).
 
+Counters are also kept while a job's step is traced: code that the step
+runs calls ``count(name)`` as it is traced, and ``counting(counters)``
+routes those calls into a log's counters (the adaptor does so around the
+trace of each step it compiles). Such a count is per trace, not per
+execution: it says which path the compiled program took, for instance
+``ssm_scan.kernel`` or ``ssm_scan.xla`` (``models/ssm.py``).
+
 Each span is mirrored into the profiler as a
 ``jax.profiler.TraceAnnotation`` named ``salus.<name>:<job>`` (``salus.<name>``
 for a span of no job), which costs about a microsecond and records nothing
@@ -24,13 +31,17 @@ Span times are measurements only: no scheduling decision reads them.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
 from collections import defaultdict, deque
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, Iterator, List, Optional
 
 import jax
 
 BOUND = 1 << 16  # spans kept
+
+_tracing = threading.local()  # .counters: where this thread's count() calls go
 
 
 class Span:
@@ -97,3 +108,22 @@ class SpanLog:
             if s.parent is not None and id(s.parent) in inner:
                 inner[id(s.parent)] += s.t1 - s.t0
         return [s.t1 - s.t0 - inner[id(s)] for s in spans]
+
+
+@contextlib.contextmanager
+def counting(counters: Dict[str, int]) -> Iterator[Dict[str, int]]:
+    """Routes the ``count`` calls this thread makes inside the block into
+    ``counters``."""
+    prev = getattr(_tracing, "counters", None)
+    _tracing.counters = counters
+    try:
+        yield counters
+    finally:
+        _tracing.counters = prev
+
+
+def count(name: str) -> None:
+    """Adds one to ``name`` in the counters ``counting`` routes to, if any."""
+    counters = getattr(_tracing, "counters", None)
+    if counters is not None:
+        counters[name] = counters.get(name, 0) + 1

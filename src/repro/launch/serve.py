@@ -32,6 +32,8 @@ from repro.core.tracegen import poisson_arrivals
 from repro.launch.cache import enable_compile_cache
 from repro.models import Model, ModelOptions, build_model
 
+# On a TPU the SSM scan runs its Pallas kernel where it fits (models/ssm.py),
+# and ssm_chunk only sizes the XLA scan it falls back to.
 _MODEL_OPTS = ModelOptions(loss_chunk=8, moe_group=16, wkv_chunk=8, ssm_chunk=8)
 
 

@@ -1,9 +1,15 @@
 """Mamba-style selective SSM branch (hymba's parallel SSM heads).
 
-Three paths, one math:
-  * ``ssm_scan_ref``      — step-by-step lax.scan (oracle + decode),
+Four paths, one math:
+  * ``ssm_scan_ref``      — step-by-step lax.scan (oracle; the chunked
+                            form's fallback),
   * ``ssm_scan_chunked``  — chunk-sequential / intra-chunk-parallel
                             (associative-scan) form used for train/prefill,
+  * the Pallas kernels (``repro.kernels.ssm_scan``), which take the place
+    of ``ssm_scan_chunked`` in ``ssm_apply`` where ``ops.fits`` says they
+    run: on a TPU, unsharded, at shapes that tile (hymba's 3200 channels
+    do). The trace counts which path each call took (``ssm_scan.kernel``
+    or ``ssm_scan.xla``, ``core/spans.py``),
   * decode single-step with conv ring state.
 
 The recurrence (diagonal A, per-channel dt):
@@ -19,7 +25,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core import spans
 from repro.dist.api import constrain
+from repro.kernels.ssm_scan import ops as scan_ops
 from repro.models.layers import Params, dense_init
 
 
@@ -158,17 +166,20 @@ def ssm_apply(
     xin: jnp.ndarray,  # (b, s, d_model)
     *,
     chunk: int = 128,
-    use_chunked: bool = True,
     return_state: bool = False,
 ):
     """Full-sequence SSM branch (train / prefill). With ``return_state``,
-    also returns (h_final (b,c,n), conv ring state (b, conv_w-1, c))."""
+    also returns (h_final (b,c,n), conv ring state (b, conv_w-1, c)).
+    ``chunk`` sizes the XLA scan; where the kernel runs it is unused."""
     xz = jnp.einsum("bsd,dc->bsc", xin, p["in_proj"])
     xz = constrain(xz, ("data", None, "model"))
     x, z, dt, b_in, c_in, a = _ssm_inputs(p, cfg, xz)
-    scan = ssm_scan_chunked if use_chunked else ssm_scan_ref
-    kw = {"chunk": chunk} if use_chunked else {}
-    y, h_final = scan(dt, a, b_in, c_in, x, **kw)
+    if scan_ops.fits(*dt.shape[1:]):
+        spans.count("ssm_scan.kernel")
+        y, h_final = scan_ops.ssm_scan(dt, a, b_in, c_in, x)
+    else:
+        spans.count("ssm_scan.xla")
+        y, h_final = ssm_scan_chunked(dt, a, b_in, c_in, x, chunk=chunk)
     y = y + p["d_skip"] * x.astype(jnp.float32)
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(xin.dtype)
     out = jnp.einsum("bsc,cd->bsd", y, p["out_proj"])

@@ -3,8 +3,9 @@
 The TPU compiler refuses here what the chip would refuse: a block not
 aligned to the tiling, a primitive Mosaic cannot lower, a program larger
 than the device. These tests hold the full-width hymba-1.5b steps of
-``launch/serve.py`` and the three Pallas kernels, at the head shapes of the
-configurations that use them, to that compiler. They run nothing.
+``launch/serve.py``, with the selective-scan kernel selected as on the chip,
+and the four Pallas kernels, at the shapes of the configurations that use
+them, to that compiler. They run nothing.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test worker
@@ -21,6 +22,7 @@ from repro.core.profiles import profile_executable
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.fused_rmsnorm.ops import rmsnorm
 from repro.kernels.rwkv_scan.ops import wkv6
+from repro.kernels.ssm_scan import ops as ssm_scan_ops
 from repro.launch.serve import serving_model, service_fns, trainer_fns
 
 V5E_HBM = 16 * 2**30
@@ -63,7 +65,9 @@ def _compile(fn, sharding, *args):
 
 
 @pytest.mark.parametrize("job", ["service", "trainer"])
-def test_hymba_full_width_step_fits_one_chip(one_chip, job):
+def test_hymba_full_width_step_fits_one_chip(one_chip, monkeypatch, job):
+    # the program asks the default backend, the CPU here, whether it runs on a TPU
+    monkeypatch.setattr(ssm_scan_ops, "on_tpu", lambda: True)
     model = serving_model("hymba-1.5b", smoke=False)
     if job == "service":
         _, step, data_fn = service_fns(model)
@@ -71,6 +75,7 @@ def test_hymba_full_width_step_fits_one_chip(one_chip, job):
         step, data_fn = trainer_fns(model)
     batch = jax.eval_shape(data_fn, 0)
     compiled = _compile(step, one_chip, model.abstract_params(), batch)
+    assert "ssm_scan_fwd" in compiled.as_text()
     profile = profile_executable(compiled)
     # P alone is the 1.61 B float32 parameters
     assert profile.persistent > 6 * 10**9
@@ -102,3 +107,19 @@ def test_wkv6_compiles_at_rwkv6_7b_heads(one_chip):
     u = jax.ShapeDtypeStruct((h, d), jnp.float32)
     fn = lambda r, k, v, w, u: wkv6(r, k, v, w, u, chunk=64)
     assert "tpu_custom_call" in _compile(fn, one_chip, x, x, x, x, u).as_text()
+
+
+def test_ssm_scan_and_its_vjp_compile_at_hymba_trainer_shape(one_chip):
+    b, s, c, n = 4, 512, 3200, 16
+    rows = jax.ShapeDtypeStruct((b, s, c), jnp.float32)
+    a = jax.ShapeDtypeStruct((c, n), jnp.float32)
+    bc = jax.ShapeDtypeStruct((b, s, n), jnp.float32)
+    x = jax.ShapeDtypeStruct((b, s, c), jnp.bfloat16)
+
+    def loss(*args):
+        y, h = ssm_scan_ops.ssm_scan(*args)
+        return jnp.sum(y) + jnp.sum(h)
+
+    assert "tpu_custom_call" in _compile(ssm_scan_ops.ssm_scan, one_chip, rows, a, bc, bc, x).as_text()
+    text = _compile(jax.grad(loss, argnums=range(5)), one_chip, rows, a, bc, bc, x).as_text()
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
